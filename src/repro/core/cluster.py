@@ -64,10 +64,13 @@ class ComputeCluster:
                  memory_model=None, region: str = "local",
                  strategy=None, max_queue_depth: int = 0,
                  scheduler_config: Optional[SchedulerConfig] = None,
-                 completion_model=None):
+                 completion_model=None, device: Any = None):
         self.net = net
         self.name = name
         self.chips = chips
+        # the accelerator device this cluster's executors place work on
+        # (a jax Device); None means the process's default device
+        self.device = device
         self.hbm_gb_per_chip = hbm_gb_per_chip
         self.region = region
         self.lake = lake

@@ -451,17 +451,26 @@ class LidcClient:
     def submit(self, fields: Dict[str, Any], retries: int = 3,
                lifetime: float = 4.0) -> Optional[JobHandle]:
         """Express a compute Interest; returns a handle with the receipt."""
-        name = canonical_job_name(fields)
-        box: Dict[str, Any] = {}
-        self.consumer.express(
-            Interest(name=name, lifetime=lifetime, must_be_fresh=True),
-            on_data=lambda d: box.__setitem__("data", d),
-            on_fail=lambda r: box.__setitem__("error", r),
-            retries=retries)
+        return self.submit_many([fields], retries, lifetime)[0]
+
+    def submit_many(self, fields_list: List[Dict[str, Any]],
+                    retries: int = 3, lifetime: float = 4.0
+                    ) -> List[Optional[JobHandle]]:
+        """Express several compute Interests at once, so the network can
+        place them side by side; one handle (or None) per job."""
+        boxes = []
+        for fields in fields_list:
+            name = canonical_job_name(fields)
+            box: Dict[str, Any] = {}
+            self.consumer.express(
+                Interest(name=name, lifetime=lifetime, must_be_fresh=True),
+                on_data=lambda d, box=box: box.__setitem__("data", d),
+                on_fail=lambda r, box=box: box.__setitem__("error", r),
+                retries=retries)
+            boxes.append((name, box))
         self.net.run()
-        if "data" not in box:
-            return None
-        return JobHandle(request_name=name, receipt=box["data"].json())
+        return [JobHandle(request_name=name, receipt=box["data"].json())
+                if "data" in box else None for name, box in boxes]
 
     def poll_until_done(self, handle: JobHandle, *, interval: float = 0.5,
                         max_polls: int = 10_000,
@@ -522,13 +531,19 @@ class LidcClient:
     def run_job(self, fields: Dict[str, Any], **poll_kw
                 ) -> Optional[JobHandle]:
         """submit → poll → fetch, the full paper workflow (Fig. 5)."""
-        handle = self.submit(fields)
-        if handle is None:
-            return None
-        self.poll_until_done(handle, **poll_kw)
-        if handle.state == "Completed":
-            self.fetch_result(handle)
-        return handle
+        return self.run_jobs([fields], **poll_kw)[0]
+
+    def run_jobs(self, fields_list: List[Dict[str, Any]], **poll_kw
+                 ) -> List[Optional[JobHandle]]:
+        """:meth:`run_job` for jobs submitted together (:meth:`submit_many`)."""
+        handles = self.submit_many(fields_list)
+        for handle in handles:
+            if handle is None:
+                continue
+            self.poll_until_done(handle, **poll_kw)
+            if handle.state == "Completed":
+                self.fetch_result(handle)
+        return handles
 
 
 # ---------------------------------------------------------------------------
@@ -556,12 +571,13 @@ class LidcSystem:
                     latency: float = 0.002, hbm_gb_per_chip: float = 16.0,
                     memory_model=None, validators=None,
                     max_queue_depth: int = 0, scheduler_config=None,
-                    legacy_nack: bool = False) -> ComputeCluster:
+                    legacy_nack: bool = False, device=None) -> ComputeCluster:
         cluster = ComputeCluster(self.net, name, chips=chips,
                                  hbm_gb_per_chip=hbm_gb_per_chip,
                                  lake=self.lake, memory_model=memory_model,
                                  max_queue_depth=max_queue_depth,
-                                 scheduler_config=scheduler_config)
+                                 scheduler_config=scheduler_config,
+                                 device=device)
         for e in endpoints:
             cluster.add_endpoint(e)
         self.overlay.add_cluster(cluster, latency=latency,

@@ -94,7 +94,8 @@ class DataLake:
         import io
         buf = io.BytesIO()
         np.savez(buf, **arrays)
-        return self.put_bytes(name, buf.getvalue(),
+        # the buffer's own view: a multi-GB checkpoint is not copied again
+        return self.put_bytes(name, buf.getbuffer(),
                               meta={"kind": "arrays", "n": len(arrays)})
 
     # ------------------------------------------------------------------ get

@@ -7,8 +7,10 @@ query heads of that kv head together (a (group x hd) tile), streaming the
 cache in ``block_k`` tiles with an online-softmax running state — so the
 cache is read exactly once.
 
-The valid cache length arrives via scalar prefetch (SMEM) and masks the
-tail tile.
+Each row's valid cache length arrives via scalar prefetch (SMEM, one
+int32 per batch row, indexed by the batch program id) and masks the tail
+tiles — so a continuous batch whose slots sit at different positions
+decodes in one call.
 """
 
 from __future__ import annotations
@@ -19,9 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax < 0.5 names it TPUCompilerParams; newer releases CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 __all__ = ["flash_decode"]
 
@@ -44,8 +43,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     v = v_ref[...].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    # mask positions beyond the valid cache length
-    length = len_ref[0]
+    # mask positions beyond this row's valid cache length
+    length = len_ref[pl.program_id(0)]
     kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(kpos < length, s, _NEG_INF)
 
@@ -67,20 +66,25 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 def flash_decode(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                  length: jax.Array, *, block_k: int = 512,
                  interpret: bool = False) -> jax.Array:
-    """q: (B, 1, H, hd); cache_k/v: (B, Smax, K, hd). Returns (B,1,H,hd)."""
+    """q: (B, 1, H, hd); cache_k/v: (B, Smax, K, hd); length: scalar or
+    (B,) valid lengths.  Returns (B, 1, H, hd)."""
     B, one, H, hd = q.shape
     Smax, K = cache_k.shape[1], cache_k.shape[2]
     group = H // K
     bk = min(block_k, Smax)
-    assert Smax % bk == 0, (Smax, bk)
     scale = hd ** -0.5
 
     qt = q.reshape(B, K, group, hd)                  # heads grouped by kv head
     kt = cache_k.transpose(0, 2, 1, 3)               # (B, K, Smax, hd)
     vt = cache_v.transpose(0, 2, 1, 3)
-    lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (1,))
+    pad = -Smax % bk                                 # masked by the lengths
+    if pad:
+        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    lengths = jnp.broadcast_to(
+        jnp.reshape(jnp.asarray(length, jnp.int32), (-1,)), (B,))
 
-    grid = (B, K, Smax // bk)
+    grid = (B, K, (Smax + pad) // bk)
     kernel = functools.partial(_decode_kernel, scale=scale, bk=bk)
     out = pl.pallas_call(
         kernel,
@@ -104,7 +108,7 @@ def flash_decode(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, group, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, qt, kt, vt)

@@ -7,6 +7,12 @@ scratch; tile sizes are MXU-aligned multiples of 128 where the sequence
 allows.  GQA maps query head h to kv head h // (H // K) in the index maps —
 no materialized kv repetition.
 
+Any query/key length is accepted: the wrapper pads both sequences up to a
+whole number of tiles, the kernel masks the padded keys, and the causal
+mask keeps the queries aligned to the *last* ``Sq`` of the ``Sk`` keys.
+The backward pass recomputes through :func:`ref.attention_ref` (a
+``custom_vjp``), so training differentiates through the kernel.
+
 Validated against ``ref.attention_ref`` in interpret mode (CPU); the TPU
 path is the deployment target.
 """
@@ -14,15 +20,13 @@ path is the deployment target.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams; newer releases CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from . import ref
 
 __all__ = ["flash_attention"]
 
@@ -47,12 +51,14 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
+    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     if causal:
         qi = pl.program_id(2)
         qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) \
             + (seq_k - seq_q)
-        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(kpos <= qpos, s, _NEG_INF)
+    if seq_k % bk:                                # padded keys of the tail tile
+        s = jnp.where(kpos < seq_k, s, _NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -68,27 +74,21 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[...] = (acc_ref[...] / l_ref[...][:, None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "block_q", "block_k",
-                                    "interpret"))
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False
-                    ) -> jax.Array:
-    """q: (B, Sq, H, hd); k/v: (B, Sk, K, hd) with H % K == 0."""
+def _forward(q, k, v, causal: bool, block_q: int, block_k: int,
+             interpret: bool) -> jax.Array:
     B, Sq, H, hd = q.shape
     Sk, K = k.shape[1], k.shape[2]
     group = H // K
     scale = hd ** -0.5
     bq = min(block_q, Sq)
     bk = min(block_k, Sk)
-    assert Sq % bq == 0 and Sk % bk == 0, (Sq, bq, Sk, bk)
+    pq, pk = -Sq % bq, -Sk % bk                   # pad to whole tiles
 
-    qt = q.transpose(0, 2, 1, 3)                  # (B, H, Sq, hd)
-    kt = k.transpose(0, 2, 1, 3)                  # (B, K, Sk, hd)
-    vt = v.transpose(0, 2, 1, 3)
+    qt = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    kt = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    vt = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
 
-    grid = (B, H, Sq // bq, Sk // bk)
+    grid = (B, H, (Sq + pq) // bq, (Sk + pk) // bk)
     kernel = functools.partial(_attn_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, seq_q=Sq, seq_k=Sk)
     out = pl.pallas_call(
@@ -104,15 +104,44 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((None, None, bq, hd),
                                lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq + pq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),       # running max
             pltpu.VMEM((bq,), jnp.float32),       # running sum
             pltpu.VMEM((bq, hd), jnp.float32),    # accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)              # (B, Sq, H, hd)
+    return out.transpose(0, 2, 1, 3)[:, :Sq]      # (B, Sq, H, hd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, block_q, block_k, interpret):
+    return _forward(q, k, v, causal, block_q, block_k, interpret)
+
+
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
+    return _forward(q, k, v, causal, block_q, block_k, interpret), (q, k, v)
+
+
+def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+    _, vjp = jax.vjp(functools.partial(ref.attention_ref, causal=causal),
+                     *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("causal", "block_q", "block_k",
+                                    "interpret"))
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                    causal: bool = True, block_q: int = 128,
+                    block_k: int = 128, interpret: bool = False
+                    ) -> jax.Array:
+    """q: (B, Sq, H, hd); k/v: (B, Sk, K, hd) with H % K == 0."""
+    return _flash(q, k, v, causal, block_q, block_k, interpret)

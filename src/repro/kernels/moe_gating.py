@@ -18,9 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams; newer releases CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["moe_gating"]
 
 
@@ -50,18 +47,19 @@ def moe_gating(logits: jax.Array, k: int, *, block_t: int = 256,
     """logits: (T, E) -> (weights (T,k) f32, ids (T,k) i32)."""
     T, E = logits.shape
     bt = min(block_t, T)
-    assert T % bt == 0, (T, bt)
+    pad = -T % bt                     # whole token tiles; padded rows dropped
+    logits = jnp.pad(logits, ((0, pad), (0, 0)))
     kernel = functools.partial(_gating_kernel, k=k)
     w, ids = pl.pallas_call(
         kernel,
-        grid=(T // bt,),
+        grid=((T + pad) // bt,),
         in_specs=[pl.BlockSpec((bt, E), lambda t: (t, 0))],
         out_specs=[pl.BlockSpec((bt, k), lambda t: (t, 0)),
                    pl.BlockSpec((bt, k), lambda t: (t, 0))],
-        out_shape=[jax.ShapeDtypeStruct((T, k), jnp.float32),
-                   jax.ShapeDtypeStruct((T, k), jnp.int32)],
-        compiler_params=_CompilerParams(
+        out_shape=[jax.ShapeDtypeStruct((T + pad, k), jnp.float32),
+                   jax.ShapeDtypeStruct((T + pad, k), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(logits)
-    return w, ids
+    return w[:T], ids[:T]

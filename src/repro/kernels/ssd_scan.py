@@ -20,9 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams; newer releases CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["ssd_state_scan"]
 
 
@@ -82,7 +79,7 @@ def ssd_state_scan(chunk_states: jax.Array, chunk_decays: jax.Array,
             jax.ShapeDtypeStruct((B, C, H, P, N), jnp.float32),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(decays, chunk_states, init_state)

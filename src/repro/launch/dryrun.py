@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 This is how the distribution config is proven coherent without hardware:
@@ -9,10 +6,21 @@ pod, (2,16,16) two pods — and every cell's step function must lower,
 SPMD-partition and compile.  ``memory_analysis()`` proves the per-device
 footprint; ``cost_analysis()`` + the HLO collective parse feed §Roofline.
 
+The module pins its process to the CPU platform before jax is imported
+(and appends its device-count flag to ``XLA_FLAGS``), so a dry run on a
+machine with a chip never takes the chip.
+
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-1.7b \
         --shape train_4k --mesh single
     PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
 """
+
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512")))
 
 import argparse
 import json
@@ -116,7 +124,7 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
                             "remat": remat, "microbatch": microbatch,
                             "compress_pods": compress_pods}
 
-    with mesh:
+    with jax.set_mesh(mesh):
         ns = lambda spec_tree: jax.tree.map(
             lambda s: NamedSharding(mesh, s), spec_tree)
         if shape.kind == "train":

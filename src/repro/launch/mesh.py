@@ -1,31 +1,42 @@
 """Mesh construction + per-arch axis rules.
 
-``make_production_mesh`` is a FUNCTION (not a module constant) so importing
-this module never touches jax device state — the dry-run sets
+Every mesh is built by :func:`make_mesh` with ``Auto`` axis types: the
+sharding rules place arrays with ``with_sharding_constraint``, which only
+refers to Auto axes (``jax.make_mesh`` defaults to Explicit).  Meshes are
+built by FUNCTIONS (not module constants) so importing this module never
+touches jax device state — the dry-run sets
 ``xla_force_host_platform_device_count`` *before* first jax init.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 
 from ..configs.base import ArchConfig
 from ..models.sharding import AxisRules, DEFAULT_RULES
 
-__all__ = ["make_production_mesh", "make_local_mesh", "rules_for"]
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh",
+           "rules_for"]
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """1-device mesh with the production axis names (smoke tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # FSDP threshold: params whose bf16 copy + fp32 moments cannot be

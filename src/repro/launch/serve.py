@@ -25,8 +25,15 @@ def main() -> None:
 
     import jax
     from ..configs.base import get_config, smoke_of
+    from ..kernels.ops import get_impl
     from ..models import bundle_for
     from ..serve.engine import ServeEngine
+    from .compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+    dev = jax.devices()[0]
+    print(f"device platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(jax.devices())} kernels={get_impl()}")
 
     cfg = smoke_of(args.arch) if args.smoke else get_config(args.arch)
     bundle = bundle_for(cfg)

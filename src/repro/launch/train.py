@@ -38,6 +38,8 @@ def main() -> None:
     ap.add_argument("--chips", type=int, default=8)
     args = ap.parse_args()
 
+    from .compile_cache import configure_compile_cache
+    configure_compile_cache()
     from ..configs.base import get_config, smoke_of
     cfg = smoke_of(args.arch) if args.smoke else get_config(args.arch)
 
@@ -50,9 +52,12 @@ def main() -> None:
                   "chips": args.chips, "steps": args.steps}
         print(f"submitting {fields} into a {args.clusters}-cluster overlay")
         handle = sys_.client.run_job(fields)
-        assert handle is not None, "no cluster answered"
+        if handle is None:
+            raise SystemExit("no cluster answered")
         print("state:", handle.state)
         print(json.dumps(handle.result or {}, indent=1, default=str))
+        if handle.state != "Completed":
+            raise SystemExit(f"job {handle.state}: {handle.error}")
         return
 
     from ..datalake import DataLake, DirStore

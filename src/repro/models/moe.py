@@ -168,14 +168,7 @@ def moe_block(cfg: ArchConfig, p: Params, x: jax.Array
     ep = E % tp_size == 0 and rules.get("expert")
     E_loc = E // tp_size if ep else E
 
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            raise ValueError
-    except Exception:
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-
+    mesh = jax.sharding.get_abstract_mesh()
     batch_axes = logical_to_pspec(("batch",), (B,))[0]
     xspec = P(batch_axes, None, None)
     if ep:
@@ -205,10 +198,9 @@ def moe_block(cfg: ArchConfig, p: Params, x: jax.Array
             aux = jax.lax.psum(aux, tp_axis)       # sum of expert slices
         return y.reshape(Bl, Sl, Dl), aux
 
-    from ..compat import shard_map
-    manual = {a for a in mesh_axes}
-    y, aux = shard_map(local_fn, mesh=mesh, in_specs=(xspec, wspec),
-                       out_specs=(xspec, P()), axis_names=manual)(x, p)
+    y, aux = jax.shard_map(local_fn, mesh=mesh, in_specs=(xspec, wspec),
+                           out_specs=(xspec, P()), axis_names=set(mesh_axes),
+                           check_vma=False)(x, p)
     return y, aux
 
 

@@ -68,36 +68,14 @@ def use_rules(rules: AxisRules):
 
 
 def _mesh_axes() -> Dict[str, int]:
-    """Axis sizes of the mesh currently in context (empty if none)."""
-    mesh = None
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not am.empty:
-            mesh = am
-    except Exception:
-        mesh = None
-    if mesh is None:
-        try:
-            from jax._src import mesh as mesh_lib
-            env = mesh_lib.thread_resources.env
-            if env.physical_mesh is not None and env.physical_mesh.devices.size:
-                mesh = env.physical_mesh
-        except Exception:
-            mesh = None
-    if mesh is None:
+    """Axis sizes of the mesh set by ``jax.set_mesh`` (empty if none).
+
+    Axes already in Manual mode (inside a shard_map) are not available to
+    with_sharding_constraint / auto partitioning — they are dropped."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return {}
-    shp = dict(mesh.shape)  # Mapping axis_name -> size (Mesh & AbstractMesh)
-    # Axes already in Manual mode (inside a shard_map) are not available to
-    # with_sharding_constraint / auto partitioning — drop them.
-    try:
-        types = getattr(mesh, "_name_to_type", None)
-        if types:
-            manual = {str(n) for n, t in types.items()
-                      if "Manual" in str(t)}
-            shp = {k: v for k, v in shp.items() if k not in manual}
-    except Exception:
-        pass
-    return shp
+    return {k: v for k, v in mesh.shape.items() if k not in mesh.manual_axes}
 
 
 def _resolve(logical: Logical, mesh_axes: Dict[str, int], dim: Optional[int]
@@ -200,8 +178,8 @@ def _strip_stack(path: str, arr_ndim: int, rule_ndim: int) -> int:
 def param_pspecs(params: Any) -> Any:
     """PartitionSpec pytree for a parameter pytree, via path rules.
 
-    Works under an active mesh context; call inside ``with mesh:`` (or an
-    abstract-mesh context) after :func:`set_rules`.
+    Works under an active mesh context; call inside
+    ``with jax.set_mesh(mesh):`` after :func:`set_rules`.
     """
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
 

@@ -39,8 +39,7 @@ def compressed_psum_pod(x: jax.Array, axis_name: str = "pod") -> jax.Array:
     of the int8 result: every element crosses the pod links exactly twice
     as one byte instead of four.
     """
-    from ..compat import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     flat = x.reshape(-1)

@@ -14,13 +14,14 @@ from ..configs.base import SHAPES, registry
 from ..core.matchmaker import ServiceEndpoint
 from ..core.overlay import LidcSystem
 from ..core.strategy import Strategy
-from .executors import (blast_executor, make_serve_executor,
-                        make_train_executor, memory_model)
+from .executors import (ServeExecutor, blast_executor, make_train_executor,
+                        memory_model)
 
-__all__ = ["build_fleet", "resilient_run"]
+__all__ = ["build_fleet", "resilient_run", "standard_endpoints"]
 
 
-def standard_endpoints(archs: Sequence[str], *, ckpt_every: int = 10
+def standard_endpoints(archs: Sequence[str], *, ckpt_every: int = 10,
+                       serve: Optional[ServeExecutor] = None
                        ) -> List[ServiceEndpoint]:
     shapes = tuple(SHAPES) + ("custom",)
     return [
@@ -29,7 +30,7 @@ def standard_endpoints(archs: Sequence[str], *, ckpt_every: int = 10
                         executor=make_train_executor(ckpt_every=ckpt_every)),
         ServiceEndpoint(service="serve-lm.lidck8s.svc.cluster.local",
                         app="serve", archs=tuple(archs), shapes=shapes,
-                        executor=make_serve_executor()),
+                        executor=serve or ServeExecutor()),
         ServiceEndpoint(service="magicblast.lidck8s.svc.cluster.local",
                         app="blast", executor=blast_executor),
     ]

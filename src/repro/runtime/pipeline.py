@@ -32,7 +32,7 @@ __all__ = ["make_pp_loss_fn", "make_pp_mesh"]
 
 
 def make_pp_mesh(n_stages: int, extra_axes: Tuple[Tuple[str, int], ...] = ()):
-    from ..compat import make_mesh
+    from ..launch.mesh import make_mesh
     shape = (n_stages,) + tuple(n for _, n in extra_axes)
     names = ("pipe",) + tuple(a for a, _ in extra_axes)
     return make_mesh(shape, names)
@@ -87,11 +87,10 @@ def make_pp_loss_fn(cfg: ArchConfig, mesh, *, n_stages: int, n_micro: int):
         # scalar psum, which old shard_map cannot transpose through
         return loss_sum.reshape(1)
 
-    from ..compat import shard_map
-    smapped = shard_map(
+    smapped = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(P("pipe"), P(), P(), P(), P(), P()),
-        out_specs=P("pipe"), axis_names={"pipe"})
+        out_specs=P("pipe"), axis_names={"pipe"}, check_vma=False)
 
     def loss_fn(params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
         blocks = jax.tree.map(

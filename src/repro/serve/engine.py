@@ -14,6 +14,11 @@ decode state can be exported as a *named KV checkpoint*
 (:meth:`kv_checkpoint`) and restored into a fresh engine on another
 cluster (:meth:`restore`) — greedy decode then continues bit-identically,
 which is what makes mid-stream cluster failover invisible to clients.
+
+An engine given a ``device`` keeps its weights and KV cache there, so one
+process can run one engine per chip.  ``record_logits=n`` keeps each
+request's first ``n`` next-token logit rows (prefill, then decode steps)
+on the host, for comparison against a reference implementation.
 """
 
 from __future__ import annotations
@@ -57,26 +62,32 @@ class Request:
     priority: int = 0
     out: List[int] = field(default_factory=list)
     done: bool = False
+    logits: List[np.ndarray] = field(default_factory=list)
 
 
 class ServeEngine:
     def __init__(self, cfg: ArchConfig, params, *, max_batch: int = 4,
-                 max_seq: int = 256, greedy: bool = True):
+                 max_seq: int = 256, greedy: bool = True, device=None,
+                 record_logits: int = 0):
         if cfg.family not in SUPPORTED_FAMILIES:
             raise UnsupportedFamilyError(cfg.family)
         self.cfg = cfg
-        self.params = params
         self.max_batch = max_batch
         self.max_seq = max_seq
+        self.record_logits = record_logits
         bundle = bundle_for(cfg)
         self._decode = jax.jit(
             lambda p, c, t: bundle.decode_step(cfg, p, c, t))
         self._prefill = jax.jit(
             lambda p, t: bundle.prefill(cfg, p, t, max_seq=max_seq),
             static_argnames=())
-        self.cache = bundle.init_cache(cfg, max_batch, max_seq)
+        cache = bundle.init_cache(cfg, max_batch, max_seq)
         # vectorized per-slot positions
-        self.cache["index"] = jnp.zeros((max_batch,), jnp.int32)
+        cache["index"] = jnp.zeros((max_batch,), jnp.int32)
+        if device is not None:
+            params, cache = jax.device_put((params, cache), device)
+        self.params = params
+        self.cache = cache
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.last_tokens = np.zeros((max_batch, 1), np.int32)
         self.queue: List[Request] = []
@@ -133,6 +144,8 @@ class ServeEngine:
         self.cache["index"] = self.cache["index"].at[slot].set(
             len(req.prompt))
         nxt = int(jnp.argmax(logits[0, -1]))
+        if self.record_logits:
+            req.logits.append(np.asarray(logits[0, -1], np.float32))
         req.out.append(nxt)
         self.tokens_out += 1
         self.last_tokens[slot, 0] = nxt
@@ -153,12 +166,18 @@ class ServeEngine:
         tokens = jnp.asarray(self.last_tokens)
         logits, self.cache = self._decode(self.params, self.cache, tokens)
         nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1), np.int32)
+        rows = None
+        if any(r is not None and len(r.logits) < self.record_logits
+               for r in self.slots):
+            rows = np.asarray(logits[:, 0, :], np.float32)
         self.decode_steps += 1
         finished: List[Request] = []
         for i, req in enumerate(self.slots):
             if req is None:
                 continue
             tok = int(nxt[i])
+            if rows is not None and len(req.logits) < self.record_logits:
+                req.logits.append(rows[i])
             req.out.append(tok)
             self.tokens_out += 1
             self.last_tokens[i, 0] = tok

@@ -86,11 +86,10 @@ def make_train_step(cfg: ArchConfig, optimizer: AdamW, *,
 
             pspec = jax.tree.map(lambda _: P(), params)
             bspec = jax.tree.map(lambda _: P("pod"), batch)
-            from ..compat import shard_map
-            return shard_map(
+            return jax.shard_map(
                 per_pod, mesh=mesh, in_specs=(pspec, bspec),
                 out_specs=(P(), pspec),
-                axis_names={"pod"})(params, batch)
+                axis_names={"pod"}, check_vma=False)(params, batch)
 
         grad_fn = grads_compressed
     else:

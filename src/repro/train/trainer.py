@@ -90,7 +90,8 @@ def run_training(cfg: ArchConfig, *, steps: int, batch: int = 8,
                 and (step + 1) % ckpt_every == 0):
             save_checkpoint(lake, run_name, step + 1, state,
                             meta={"loss": loss})
-    if lake is not None and ckpt_every > 0 and result.steps_done > start_step:
+    if (lake is not None and ckpt_every > 0 and result.steps_done > start_step
+            and result.steps_done % ckpt_every):      # not saved in the loop
         save_checkpoint(lake, run_name, result.steps_done, state,
                         meta={"loss": result.final_loss})
     result.wall_time = time.time() - t0

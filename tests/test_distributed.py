@@ -9,12 +9,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-OLD_JAX = not hasattr(jax, "shard_map")   # jax<0.5: experimental shard_map
 
 
 def run_py(code: str, devices: int = 8, timeout: int = 560) -> str:
@@ -37,14 +32,14 @@ def test_param_pspecs_rules_and_divisibility():
         from repro.models.sharding import param_pspecs, set_rules
         from repro.launch.mesh import rules_for
 
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("qwen3-1.7b")
         set_rules(rules_for(cfg, model_axis=4))
         bundle = bundle_for(cfg)
         shapes = jax.eval_shape(lambda k: bundle.init(cfg, k),
                                 jax.ShapeDtypeStruct((2,), jnp.uint32))
-        with mesh:
+        with jax.set_mesh(mesh):
             specs = param_pspecs(shapes)
         wq = specs["blocks"]["attn"]["wq"]
         assert wq == P(None, None, "model"), wq      # stacked leading dim
@@ -64,7 +59,7 @@ def test_compressed_psum_matches_plain_psum():
         from jax.sharding import PartitionSpec as P
         from repro.optim.compress import compressed_psum_pod
 
-        from repro.compat import make_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((4, 2), ("pod", "data"))
         x = jnp.asarray(np.random.default_rng(0).normal(
             size=(4, 64)).astype(np.float32))
@@ -75,8 +70,9 @@ def test_compressed_psum_matches_plain_psum():
         def compressed(x):
             return compressed_psum_pod(x, "pod")
 
-        sm = lambda f: jax.jit(shard_map(
-            f, mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None)))
+        sm = lambda f: jax.jit(jax.shard_map(
+            f, mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None),
+            check_vma=False))
         a = sm(plain)(x)
         b = sm(compressed)(x)
         err = float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(a)) + 1e-9))
@@ -106,10 +102,10 @@ def test_moe_expert_parallel_matches_single_device():
         y_ref, aux_ref = MoE.moe_block(cfg, p, x)
 
         # expert-parallel over a 4-way model axis
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         set_rules(rules_for(cfg, model_axis=4, force_tp=True))
-        with mesh:
+        with jax.set_mesh(mesh):
             y_ep, aux_ep = jax.jit(
                 lambda p, x: MoE.moe_block(cfg, p, x))(p, x)
         err = float(jnp.max(jnp.abs(y_ref - y_ep)))
@@ -135,11 +131,11 @@ def test_grad_shardings_lower_and_compile():
 
         cfg = smoke_of("qwen3-1.7b")
         shape = ShapeConfig("t", "train", 64, 8)
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         set_rules(rules_for(cfg, model_axis=4))
         opt = AdamW(lr=constant(1e-4))
-        with mesh:
+        with jax.set_mesh(mesh):
             st = train_state_shape(cfg, opt)
             sspec = param_pspecs(st)
             ns = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t)
@@ -157,9 +153,6 @@ def test_grad_shardings_lower_and_compile():
     assert "MINI_DRYRUN_OK" in out
 
 
-@pytest.mark.xfail(OLD_JAX, strict=False,
-                   reason="jax<0.5 rejects sharding constraints that mention "
-                          "a manual axis inside a partial-auto shard_map")
 def test_multipod_compressed_train_step_lowers():
     """Cross-pod int8 gradient compression inside the jitted train step."""
     out = run_py("""
@@ -173,11 +166,11 @@ def test_multipod_compressed_train_step_lowers():
 
         cfg = smoke_of("qwen2-0.5b")
         shape = ShapeConfig("t", "train", 32, 8)
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         set_rules(rules_for(cfg, model_axis=2))
         opt = AdamW(lr=constant(1e-4))
-        with mesh:
+        with jax.set_mesh(mesh):
             st = train_state_shape(cfg, opt)
             sspec = param_pspecs(st)
             ns = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t)

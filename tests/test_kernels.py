@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.decode_attention import flash_decode
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_gating import moe_gating
@@ -50,6 +50,43 @@ def test_flash_attention_dtypes(dtype):
                                rtol=atol)
 
 
+@pytest.mark.parametrize("Sq,Sk", [
+    (1, 1), (200, 200), (300, 300),      # prompt lengths off the 128 tile
+    (72, 200),                           # padded suffix queries
+])
+def test_flash_attention_any_length(Sq, Sk):
+    """Default 128 tiles: the wrapper pads both sequences and masks."""
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (1, Sq, 4, 64), jnp.float32)
+    k = jax.random.normal(ks[1], (1, Sk, 2, 64), jnp.float32)
+    v = jax.random.normal(ks[2], (1, Sk, 2, 64), jnp.float32)
+    out = flash_attention(q, k, v, interpret=True)
+    want = ref.attention_ref(q, k, v)
+    assert out.shape == want.shape
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+
+
+def test_attention_grad_matches_reference():
+    """jax.grad through the kernel (custom_vjp) equals the reference's."""
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (2, 200, 4, 32), jnp.float32)
+    k = jax.random.normal(ks[1], (2, 200, 2, 32), jnp.float32)
+    v = jax.random.normal(ks[2], (2, 200, 2, 32), jnp.float32)
+    w = jax.random.normal(ks[3], (2, 200, 4, 32), jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.attention(q, k, v) * w)
+
+    grads = {}
+    for impl in ("interpret", "ref"):
+        with ops.use_impl(impl):
+            grads[impl] = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+    (l_k, g_k), (l_r, g_r) = grads["interpret"], grads["ref"]
+    np.testing.assert_allclose(l_k, l_r, rtol=1e-5)
+    for a, b in zip(g_k, g_r):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
 def test_chunked_attention_matches_ref():
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (2, 512, 4, 32), jnp.float32)
@@ -78,6 +115,21 @@ def test_flash_decode(B, Smax, H, K, hd, length, bk):
     out = flash_decode(q, ck, cv, jnp.asarray(length), block_k=bk,
                        interpret=True)
     want = ref.decode_attention_ref(q, ck, cv, jnp.asarray(length))
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("Smax,bk", [(512, 128), (300, 128)])
+def test_flash_decode_ragged_lengths(Smax, bk):
+    """Per-row (B,) lengths, as a continuous batch decodes; a cache that is
+    not a whole number of blocks is padded and masked."""
+    ks = jax.random.split(KEY, 3)
+    B, H, K, hd = 4, 8, 2, 64
+    q = jax.random.normal(ks[0], (B, 1, H, hd), jnp.float32)
+    ck = jax.random.normal(ks[1], (B, Smax, K, hd), jnp.float32)
+    cv = jax.random.normal(ks[2], (B, Smax, K, hd), jnp.float32)
+    lengths = jnp.asarray([1, 77, Smax, 129], jnp.int32)
+    out = flash_decode(q, ck, cv, lengths, block_k=bk, interpret=True)
+    want = ref.decode_attention_ref(q, ck, cv, lengths)
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 
 
@@ -136,6 +188,23 @@ def test_moe_gating(T, E, k, bt):
     w2, ids2 = ref.moe_gating_ref(logits, k)
     assert bool(jnp.all(ids == ids2))
     np.testing.assert_allclose(w, w2, atol=1e-6)
+
+
+def test_moe_gating_ragged_token_count():
+    """A token count off the block is padded and the padding dropped."""
+    logits = jax.random.normal(KEY, (300, 64), jnp.float32)
+    w, ids = moe_gating(logits, 4, block_t=128, interpret=True)
+    w2, ids2 = ref.moe_gating_ref(logits, 4)
+    assert w.shape == (300, 4) and bool(jnp.all(ids == ids2))
+    np.testing.assert_allclose(w, w2, atol=1e-6)
+
+
+def test_kernel_impl_follows_platform():
+    assert ops.get_impl() == ("pallas" if jax.default_backend() == "tpu"
+                              else "ref")
+    with ops.use_impl("interpret"):
+        assert ops.get_impl() == "interpret"
+    assert ops.get_impl() != "interpret"
 
 
 def test_moe_gating_property():

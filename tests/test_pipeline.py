@@ -4,18 +4,12 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-OLD_JAX = not hasattr(jax, "shard_map")   # jax<0.5: experimental shard_map
-
 
 @pytest.mark.slow
-@pytest.mark.xfail(OLD_JAX, strict=False,
-                   reason="jax<0.5 experimental shard_map raises _SpecError "
-                          "when transposing the pipeline stage function")
 def test_pp_loss_and_grads_match_sequential():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -42,7 +36,7 @@ ref_loss, ref_grads = jax.value_and_grad(
 
 mesh = make_pp_mesh(4)
 pp_loss_fn = make_pp_loss_fn(cfg, mesh, n_stages=4, n_micro=4)
-with mesh:
+with jax.set_mesh(mesh):
     pp_loss, pp_grads = jax.jit(jax.value_and_grad(pp_loss_fn))(params, batch)
 
 err = abs(float(ref_loss) - float(pp_loss))

@@ -3,9 +3,11 @@
 import pytest
 
 from repro.ckpt.checkpoint import latest_step
+from repro.configs.base import ShapeConfig, get_config
 from repro.core.jobs import JobSpec
 from repro.core.strategy import CompletionTimeStrategy
 from repro.core.scheduler import CompletionModel
+from repro.runtime.executors import runs_real
 from repro.runtime.fleet import build_fleet, resilient_run
 
 
@@ -91,6 +93,36 @@ def test_failover_resumes_from_named_checkpoint():
     assert attempts >= 2
     assert h.result["resumed_from"] is not None
     assert latest_step(sys_.lake, run_name) == 20
+
+
+class _Device:
+    """A device reporting ``bytes_limit`` (a chip) or no limit (the CPU)."""
+
+    def __init__(self, limit):
+        self.limit = limit
+
+    def memory_stats(self):
+        return None if self.limit is None else {"bytes_limit": self.limit}
+
+
+@pytest.mark.parametrize("arch,limit,real", [
+    ("qwen3-1.7b", 16e9, True),              # fits one 16 GB chip
+    ("qwen3-1.7b", 2e9, False),              # its weights alone do not
+    ("lidc-demo", None, True),               # no limit: small runs real
+    ("mistral-large-123b", None, False),     # no limit: 123B stays simulated
+])
+def test_executor_fit_rule(arch, limit, real):
+    shape = ShapeConfig("serve", "decode", 1024, 4)
+    assert runs_real(get_config(arch), shape, _Device(limit)) is real
+
+
+def test_serve_job_prompt_lengths_from_fields():
+    sys_ = small_fleet()
+    h = sys_.client.run_job({"app": "serve", "arch": "lidc-demo",
+                             "plens": "1,20,9", "new_tokens": 3})
+    assert h is not None and h.state == "Completed", h and h.error
+    assert h.result["real_compute"] is True
+    assert h.result["requests"] == 3 and h.result["tokens_out"] == 9
 
 
 def test_cluster_join_during_operation():
