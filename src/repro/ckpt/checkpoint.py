@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..core.names import DATA_PREFIX, Name
 
 __all__ = ["ckpt_prefix", "save_checkpoint", "restore_checkpoint",
@@ -50,11 +51,16 @@ def save_checkpoint(lake, run: str, step: int, state: Params,
                     meta: Optional[Dict[str, Any]] = None) -> Name:
     """Write the full state tree + advance the 'latest' pointer atomically
     (object first, pointer second — a torn write leaves the old pointer)."""
-    arrays = _flatten(state)
     name = ckpt_prefix(run).append(f"step={step}")
-    lake.put_arrays(name, arrays)
-    lake.put_json(ckpt_prefix(run).append("latest"),
-                  {"step": step, "run": run, **(meta or {})})
+    with tracing.span("ckpt.save", step=step) as rec:
+        with tracing.span("ckpt.device_get"):
+            arrays = _flatten(state)
+        if rec is not None:
+            rec["bytes"] = sum(a.nbytes for a in arrays.values())
+        with tracing.span("lake.put"):
+            lake.put_arrays(name, arrays)
+            lake.put_json(ckpt_prefix(run).append("latest"),
+                          {"step": step, "run": run, **(meta or {})})
     return name
 
 

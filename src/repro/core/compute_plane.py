@@ -45,6 +45,7 @@ import statistics
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from .. import tracing
 from .jobs import Job, JobSpec, result_name_for
 from .scheduler import CompletionModel
 
@@ -485,7 +486,8 @@ class ClusterScheduler:
         try:
             assert q.endpoint.executor is not None, \
                 f"{q.endpoint.service} has no executor"
-            res = q.endpoint.executor(q.job, cluster)
+            with tracing.span("lidc.exec", job=q.job.job_id):
+                res = q.endpoint.executor(q.job, cluster)
         except Exception as e:  # execution failed synchronously
             self._finish(rec, error=f"{type(e).__name__}: {e}")
             return
@@ -506,7 +508,8 @@ class ClusterScheduler:
         plan = rec.plan
         if rec.phase >= len(plan.phases):
             try:
-                res = plan.finalize()
+                with tracing.span("lidc.exec", job=rec.job.job_id):
+                    res = plan.finalize()
             except Exception as e:
                 self._finish(rec, error=f"{type(e).__name__}: {e}")
                 return
@@ -520,7 +523,8 @@ class ClusterScheduler:
             if not self.cluster.alive:
                 return  # died mid-phase: this phase's work never happened
             try:
-                work()
+                with tracing.span("lidc.exec", job=rec.job.job_id):
+                    work()
             except Exception as e:
                 self._finish(rec, error=f"{type(e).__name__}: {e}")
                 return

@@ -24,6 +24,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Set, Tuple
 
+from .. import tracing
 from .cluster import ComputeCluster
 from .forwarder import Consumer, Face, Forwarder, Network, link
 from .gateway import Gateway
@@ -536,13 +537,14 @@ class LidcClient:
     def run_jobs(self, fields_list: List[Dict[str, Any]], **poll_kw
                  ) -> List[Optional[JobHandle]]:
         """:meth:`run_job` for jobs submitted together (:meth:`submit_many`)."""
-        handles = self.submit_many(fields_list)
-        for handle in handles:
-            if handle is None:
-                continue
-            self.poll_until_done(handle, **poll_kw)
-            if handle.state == "Completed":
-                self.fetch_result(handle)
+        with tracing.span("lidc.run_jobs", jobs=len(fields_list)):
+            handles = self.submit_many(fields_list)
+            for handle in handles:
+                if handle is None:
+                    continue
+                self.poll_until_done(handle, **poll_kw)
+                if handle.state == "Completed":
+                    self.fetch_result(handle)
         return handles
 
 

@@ -41,11 +41,11 @@ def main() -> None:
     eng = ServeEngine(cfg, params, max_batch=args.max_batch,
                       max_seq=args.max_seq)
     rng = np.random.default_rng(args.seed)
-    t0 = time.time()
+    t0 = time.perf_counter()
     for i in range(args.requests):
         eng.submit(list(rng.integers(0, cfg.vocab, 8)), max_new=args.max_new)
     done = eng.run()
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     print(f"arch={cfg.arch_id} requests={len(done)} "
           f"tokens={eng.tokens_out} decode_steps={eng.decode_steps} "
           f"wall={dt:.2f}s tok/s={eng.tokens_out / max(dt, 1e-9):.1f}")

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..configs.base import ArchConfig
 from ..models.model import bundle_for
 
@@ -63,6 +64,8 @@ class Request:
     out: List[int] = field(default_factory=list)
     done: bool = False
     logits: List[np.ndarray] = field(default_factory=list)
+    # when it was submitted, on the spans' clock; set only while tracing
+    submitted: Optional[float] = None
 
 
 class ServeEngine:
@@ -100,7 +103,7 @@ class ServeEngine:
                eos: Optional[int] = None, priority: int = 0) -> Request:
         self._rid += 1
         req = Request(rid=self._rid, prompt=list(prompt), max_new=max_new,
-                      eos=eos, priority=priority)
+                      eos=eos, priority=priority, submitted=tracing.now())
         if max_new <= 0:
             # nothing to decode: finished at submission, never takes a slot
             req.done = True
@@ -126,68 +129,78 @@ class ServeEngine:
         reached or EOS on the first token) — their slot frees immediately,
         so a queued request can take it the same step."""
         finished: List[Request] = []
-        for i in range(self.max_batch):
-            while self.slots[i] is None and self.queue:
-                self.queue.sort(key=lambda r: (-r.priority, r.rid))
-                req = self.queue.pop(0)
-                self._prefill_into_slot(i, req)
-                if req.done:
-                    finished.append(req)
+        with tracing.span("serve.admit"):
+            for i in range(self.max_batch):
+                while self.slots[i] is None and self.queue:
+                    self.queue.sort(key=lambda r: (-r.priority, r.rid))
+                    req = self.queue.pop(0)
+                    self._prefill_into_slot(i, req)
+                    if req.done:
+                        finished.append(req)
         return finished
 
     def _prefill_into_slot(self, slot: int, req: Request) -> None:
-        toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        logits, c1 = self._prefill(self.params, toks)
-        # copy the single-row cache into the slot
-        self.cache["k"] = self.cache["k"].at[:, slot].set(c1["k"][:, 0])
-        self.cache["v"] = self.cache["v"].at[:, slot].set(c1["v"][:, 0])
-        self.cache["index"] = self.cache["index"].at[slot].set(
-            len(req.prompt))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        if self.record_logits:
-            req.logits.append(np.asarray(logits[0, -1], np.float32))
-        req.out.append(nxt)
-        self.tokens_out += 1
-        self.last_tokens[slot, 0] = nxt
-        self.slots[slot] = req
-        if (len(req.out) >= req.max_new
-                or (req.eos is not None and nxt == req.eos)):
-            # budget exhausted (or EOS) on the prefill token itself: the
-            # request never enters the decode loop and its slot is free
-            # for the next queued request this very step
-            req.done = True
-            self.slots[slot] = None
-            self.cache["index"] = self.cache["index"].at[slot].set(0)
+        with tracing.span("serve.prefill", rid=req.rid,
+                          prompt=len(req.prompt)) as rec:
+            if rec is not None and req.submitted is not None:
+                rec["queued_s"] = rec["t0"] - req.submitted
+            toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            logits, c1 = self._prefill(self.params, toks)
+            # copy the single-row cache into the slot
+            self.cache["k"] = self.cache["k"].at[:, slot].set(c1["k"][:, 0])
+            self.cache["v"] = self.cache["v"].at[:, slot].set(c1["v"][:, 0])
+            self.cache["index"] = self.cache["index"].at[slot].set(
+                len(req.prompt))
+            with tracing.span("serve.sync"):
+                nxt = int(jnp.argmax(logits[0, -1]))
+                if self.record_logits:
+                    req.logits.append(np.asarray(logits[0, -1], np.float32))
+            req.out.append(nxt)
+            self.tokens_out += 1
+            self.last_tokens[slot, 0] = nxt
+            self.slots[slot] = req
+            if (len(req.out) >= req.max_new
+                    or (req.eos is not None and nxt == req.eos)):
+                # budget exhausted (or EOS) on the prefill token itself:
+                # the request never enters the decode loop and its slot is
+                # free for the next queued request this very step
+                req.done = True
+                self.slots[slot] = None
+                self.cache["index"] = self.cache["index"].at[slot].set(0)
 
     def step(self) -> List[Request]:
         """One decode step for all active slots."""
-        if not any(s is not None for s in self.slots):
+        active = sum(s is not None for s in self.slots)
+        if not active:
             return []
-        tokens = jnp.asarray(self.last_tokens)
-        logits, self.cache = self._decode(self.params, self.cache, tokens)
-        nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1), np.int32)
-        rows = None
-        if any(r is not None and len(r.logits) < self.record_logits
-               for r in self.slots):
-            rows = np.asarray(logits[:, 0, :], np.float32)
-        self.decode_steps += 1
-        finished: List[Request] = []
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            tok = int(nxt[i])
-            if rows is not None and len(req.logits) < self.record_logits:
-                req.logits.append(rows[i])
-            req.out.append(tok)
-            self.tokens_out += 1
-            self.last_tokens[i, 0] = tok
-            full = len(req.prompt) + len(req.out) >= self.max_seq - 1
-            if (len(req.out) >= req.max_new or full
-                    or (req.eos is not None and tok == req.eos)):
-                req.done = True
-                finished.append(req)
-                self.slots[i] = None
-                self.cache["index"] = self.cache["index"].at[i].set(0)
+        with tracing.span("serve.step", active=active, slots=self.max_batch):
+            tokens = jnp.asarray(self.last_tokens)
+            logits, self.cache = self._decode(self.params, self.cache, tokens)
+            with tracing.span("serve.sync"):
+                nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1),
+                                 np.int32)
+                rows = None
+                if any(r is not None and len(r.logits) < self.record_logits
+                       for r in self.slots):
+                    rows = np.asarray(logits[:, 0, :], np.float32)
+            self.decode_steps += 1
+            finished: List[Request] = []
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                tok = int(nxt[i])
+                if rows is not None and len(req.logits) < self.record_logits:
+                    req.logits.append(rows[i])
+                req.out.append(tok)
+                self.tokens_out += 1
+                self.last_tokens[i, 0] = tok
+                full = len(req.prompt) + len(req.out) >= self.max_seq - 1
+                if (len(req.out) >= req.max_new or full
+                        or (req.eos is not None and tok == req.eos)):
+                    req.done = True
+                    finished.append(req)
+                    self.slots[i] = None
+                    self.cache["index"] = self.cache["index"].at[i].set(0)
         return finished
 
     # -- named KV checkpoint / restore ----------------------------------------

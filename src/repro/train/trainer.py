@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from ..configs.base import ArchConfig, ShapeConfig
 from ..data.pipeline import make_pipeline
 from ..optim.adamw import AdamW
@@ -52,23 +53,23 @@ def run_training(cfg: ArchConfig, *, steps: int, batch: int = 8,
 
     Resumes from the latest named checkpoint of ``run_name`` if one exists
     (this is what makes jobs migrate across clusters)."""
-    t0 = time.time()
+    t0 = time.perf_counter()
     shape = ShapeConfig("custom", "train", seq, batch)
     optimizer = AdamW(lr=warmup_cosine(lr, max(steps // 20, 2), steps))
-    key = jax.random.PRNGKey(seed)
-    state = make_train_state(cfg, key, optimizer)
-
     resumed_from = None
     start_step = 0
-    if lake is not None and ckpt_every > 0:
-        last = latest_step(lake, run_name)
-        if last is not None and last > 0:
-            state, start_step = restore_checkpoint(lake, run_name, state)
-            resumed_from = start_step
+    with tracing.span("train.init"):
+        state = make_train_state(cfg, jax.random.PRNGKey(seed), optimizer)
+        if lake is not None and ckpt_every > 0:
+            last = latest_step(lake, run_name)
+            if last is not None and last > 0:
+                state, start_step = restore_checkpoint(lake, run_name, state)
+                resumed_from = start_step
 
-    step_fn = jax.jit(make_train_step(cfg, optimizer, remat=remat,
-                                      microbatch=microbatch),
-                      donate_argnums=0)
+    jitted = jax.jit(make_train_step(cfg, optimizer, remat=remat,
+                                     microbatch=microbatch),
+                     donate_argnums=0)
+    step_fn = None
     pipeline = make_pipeline(cfg, shape, lake=lake, dataset=dataset,
                              seed=seed)
     it = iter(pipeline)
@@ -78,14 +79,20 @@ def run_training(cfg: ArchConfig, *, steps: int, batch: int = 8,
     for step in range(start_step, steps):
         if stop_flag is not None and stop_flag():
             break
-        batch_np = next(it)
-        batch_dev = jax.tree.map(jnp.asarray, batch_np)
-        state, metrics = step_fn(state, batch_dev)
-        loss = float(metrics["loss"])
-        result.losses.append(loss)
-        result.steps_done = step + 1
-        if on_step is not None:
-            on_step(step, loss)
+        batch_dev = jax.tree.map(jnp.asarray, next(it))
+        if step_fn is None:
+            # traced and compiled (or loaded from the compile cache) here,
+            # so no step's execution falls inside the build
+            with tracing.span("train.build"):
+                step_fn = jitted.lower(state, batch_dev).compile()
+        with tracing.span("train.step", step=step):
+            state, metrics = step_fn(state, batch_dev)
+            with tracing.span("train.sync"):
+                loss = float(metrics["loss"])
+            result.losses.append(loss)
+            result.steps_done = step + 1
+            if on_step is not None:
+                on_step(step, loss)
         if (lake is not None and ckpt_every > 0
                 and (step + 1) % ckpt_every == 0):
             save_checkpoint(lake, run_name, step + 1, state,
@@ -94,5 +101,5 @@ def run_training(cfg: ArchConfig, *, steps: int, batch: int = 8,
             and result.steps_done % ckpt_every):      # not saved in the loop
         save_checkpoint(lake, run_name, result.steps_done, state,
                         meta={"loss": result.final_loss})
-    result.wall_time = time.time() - t0
+    result.wall_time = time.perf_counter() - t0
     return result
