@@ -2,7 +2,8 @@
 
 Checkpoints are ordinary named data-lake objects::
 
-    /lidc/data/ckpt/<run>/step=<N>        (segmented npz of the state tree)
+    /lidc/data/ckpt/<run>/step=<N>        (json manifest of the state tree)
+    /lidc/data/ckpt/<run>/step=<N>/leaf=i (each leaf's raw bytes, own dtype)
     /lidc/data/ckpt/<run>/latest          (json pointer {step, run})
 
 Because the name is derived from the *job*, not the cluster, any cluster
@@ -34,33 +35,38 @@ def ckpt_prefix(run: str) -> Name:
     return Name.parse(DATA_PREFIX).append("ckpt", run)
 
 
+def _key(pathkeys) -> str:
+    return "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                    for k in pathkeys)
+
+
 def _flatten(state: Params) -> Dict[str, np.ndarray]:
-    flat = jax.tree_util.tree_flatten_with_path(state)[0]
-    out = {}
-    for pathkeys, arr in flat:
-        key = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
-                       for k in pathkeys)
-        a = jax.device_get(arr)
-        if a.dtype == jnp.bfloat16:   # numpy can't serialize bf16; f32 is
-            a = np.asarray(a, np.float32)   # a lossless container for it
-        out[key] = np.asarray(a)
-    return out
+    """The state's leaves on the host, in their own dtypes, keyed by path.
+    One ``device_get`` of the whole tree puts every transfer in flight
+    together."""
+    flat = jax.tree_util.tree_flatten_with_path(jax.device_get(state))[0]
+    return {_key(pathkeys): np.asarray(a) for pathkeys, a in flat}
 
 
 def save_checkpoint(lake, run: str, step: int, state: Params,
                     meta: Optional[Dict[str, Any]] = None) -> Name:
     """Write the full state tree + advance the 'latest' pointer atomically
-    (object first, pointer second — a torn write leaves the old pointer)."""
+    (object first, pointer second — a torn write leaves the old pointer).
+    Returns once every leaf, the manifest and the pointer are stored."""
     name = ckpt_prefix(run).append(f"step={step}")
     with tracing.span("ckpt.save", step=step) as rec:
         with tracing.span("ckpt.device_get"):
             arrays = _flatten(state)
         if rec is not None:
             rec["bytes"] = sum(a.nbytes for a in arrays.values())
-        with tracing.span("lake.put"):
+        copies = getattr(lake.store, "copies", None)   # MemoryStore only
+        with tracing.span("lake.put") as put:
             lake.put_arrays(name, arrays)
             lake.put_json(ckpt_prefix(run).append("latest"),
                           {"step": step, "run": run, **(meta or {})})
+        if put is not None:
+            put["store_copies"] = (None if copies is None
+                                   else lake.store.copies - copies)
     return name
 
 
@@ -88,8 +94,7 @@ def restore_checkpoint(lake, run: str, template: Params,
     flat_t = jax.tree_util.tree_flatten_with_path(template)
     leaves = []
     for pathkeys, tmpl in flat_t[0]:
-        key = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
-                       for k in pathkeys)
+        key = _key(pathkeys)
         if key not in arrays:
             raise KeyError(f"checkpoint missing leaf {key}")
         arr = arrays[key]

@@ -25,9 +25,11 @@ Callers must not mutate a buffer after ``put_bytes``; the store aliases it.
 
 from __future__ import annotations
 
+import io
 import json
 from typing import Any, Callable, Dict, Iterable, List, Optional
 
+import ml_dtypes  # noqa: F401  (numpy learns dtype names such as bfloat16)
 import numpy as np
 
 from ..core import reasons
@@ -90,13 +92,27 @@ class DataLake:
         return self.put_bytes(name, json.dumps(obj, sort_keys=True).encode(), **kw)
 
     def put_arrays(self, name: Name, arrays: Dict[str, np.ndarray]) -> Name:
-        """Store a flat dict of numpy arrays (checkpoint shards use this)."""
-        import io
-        buf = io.BytesIO()
-        np.savez(buf, **arrays)
-        # the buffer's own view: a multi-GB checkpoint is not copied again
-        return self.put_bytes(name, buf.getbuffer(),
-                              meta={"kind": "arrays", "n": len(arrays)})
+        """Store a flat dict of arrays (checkpoints use this) as raw leaf
+        buffers in their own dtypes, without a copy.
+
+        Leaf ``i`` is the object ``<name>/leaf=<i>``: the bytes of the
+        C-contiguous array, put through :meth:`put_bytes`, so the store
+        holds slices of the array itself.  Then ``<name>`` itself gets a
+        JSON manifest (key, dtype name, shape and byte count per leaf):
+        leaves first, manifest second, so a torn write reads as missing.
+        """
+        leaves = []
+        for i, (key, a) in enumerate(arrays.items()):
+            a = np.asarray(a)
+            a = np.asarray(a, a.dtype.newbyteorder("="), order="C")
+            # a flat uint8 view: memoryview refuses ml_dtypes' bfloat16, and
+            # slicing an n-d view would cut rows, not bytes
+            self.put_bytes(name.append(f"leaf={i}"),
+                           memoryview(a.reshape(-1).view(np.uint8)))
+            leaves.append({"key": key, "dtype": a.dtype.name,
+                           "shape": list(a.shape), "nbytes": a.nbytes})
+        return self.put_json(name, {"kind": "arrays", "leaves": leaves},
+                             meta={"kind": "arrays", "n": len(leaves)})
 
     # ------------------------------------------------------------------ get
     def get_view(self, name: Name):
@@ -135,12 +151,24 @@ class DataLake:
         return None if blob is None else json.loads(blob.decode())
 
     def get_arrays(self, name: Name) -> Optional[Dict[str, np.ndarray]]:
-        import io
-        blob = self.get_bytes(name)
+        """The arrays :meth:`put_arrays` stored, each a view of its leaf's
+        bytes where the store allows; ``None`` if the manifest or any leaf
+        is missing.  An ``np.savez`` blob (the layout of older lakes) is
+        read through ``np.load``."""
+        blob = self.get_view(name)
         if blob is None:
             return None
-        with np.load(io.BytesIO(blob)) as z:
-            return {k: z[k] for k in z.files}
+        if bytes(blob[:4]) == b"PK\x03\x04":
+            with np.load(io.BytesIO(blob)) as z:
+                return {k: z[k] for k in z.files}
+        out = {}
+        for i, leaf in enumerate(json.loads(bytes(blob).decode())["leaves"]):
+            buf = self.get_view(name.append(f"leaf={i}"))
+            if buf is None or len(buf) != leaf["nbytes"]:
+                return None
+            out[leaf["key"]] = np.frombuffer(buf, np.dtype(leaf["dtype"])
+                                             ).reshape(leaf["shape"])
+        return out
 
     def has(self, name: Name) -> bool:
         return (self.store.get(str(name)) is not None
