@@ -65,16 +65,20 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
-                     length: jax.Array, *, seq_shard: bool = False
-                     ) -> jax.Array:
+                     length: jax.Array, layer: jax.Array | int = 0, *,
+                     seq_shard: bool = False) -> jax.Array:
+    """cache_k/v: one layer's (B, Smax, K, hd), or a stacked
+    (L, B, Smax, K, hd) cache read at ``layer``."""
     # seq_shard is handled transparently by the SPMD partitioner: with the
     # cache sequence dim sharded over 'data', the softmax reductions become
     # all-reduces. The flag is kept for the explicit shard_map path.
     impl = get_impl()
     if impl == "ref":
+        if cache_k.ndim == 5:
+            cache_k, cache_v = cache_k[layer], cache_v[layer]
         return ref.decode_attention_ref(q, cache_k, cache_v, length)
     from .decode_attention import flash_decode
-    return flash_decode(q, cache_k, cache_v, length,
+    return flash_decode(q, cache_k, cache_v, length, layer,
                         interpret=(impl == "interpret"))
 
 

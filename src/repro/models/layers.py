@@ -20,7 +20,8 @@ from .sharding import shard
 __all__ = [
     "init_linear", "linear", "init_rmsnorm", "rms_norm", "init_embed",
     "embed_lookup", "rope_freqs", "apply_rope", "init_attention",
-    "attention_block", "attention_decode", "init_mlp", "mlp_block",
+    "attention_block", "attention_decode", "attention_decode_at",
+    "init_mlp", "mlp_block",
     "cross_entropy_loss",
 ]
 
@@ -201,11 +202,29 @@ def attention_decode(p: Params, x: jax.Array, cache_k: jax.Array,
                      n_kv: int, head_dim: int, theta: float = 1e6,
                      eps: float = 1e-5, seq_shard: bool = False
                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token decode against a KV cache.
+    """One-token decode against one layer's KV cache.
 
     cache_k/v: (B, S_max, K, hd); index: current length — scalar int32 for
     lockstep batches, or (B,) for continuous batching (per-slot positions).
     Returns (out (B,1,D), new_cache_k, new_cache_v).
+    """
+    o, ck, cv = attention_decode_at(p, x, cache_k[None], cache_v[None], 0,
+                                    index, n_heads=n_heads, n_kv=n_kv,
+                                    head_dim=head_dim, theta=theta, eps=eps,
+                                    seq_shard=seq_shard)
+    return o, ck[0], cv[0]
+
+
+def attention_decode_at(p: Params, x: jax.Array, cache_k: jax.Array,
+                        cache_v: jax.Array, layer: jax.Array | int,
+                        index: jax.Array, *, n_heads: int, n_kv: int,
+                        head_dim: int, theta: float = 1e6, eps: float = 1e-5,
+                        seq_shard: bool = False
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`attention_decode` at ``layer`` of a stacked
+    (L, B, S_max, K, hd) cache: the new token's K/V row is written in place
+    and attention reads the stack at that layer, so no layer is sliced out
+    or written back.  Returns (out (B,1,D), new_cache_k, new_cache_v).
     """
     from ..kernels import ops
     B, one, _ = x.shape
@@ -215,14 +234,17 @@ def attention_decode(p: Params, x: jax.Array, cache_k: jax.Array,
                            mode="decode")
     if per_slot:
         b_idx = jnp.arange(B)
-        cache_k = cache_k.at[b_idx, index].set(k[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[b_idx, index].set(v[:, 0].astype(cache_v.dtype))
+        cache_k = cache_k.at[layer, b_idx, index].set(
+            k[:, 0].astype(cache_k.dtype))
+        cache_v = cache_v.at[layer, b_idx, index].set(
+            v[:, 0].astype(cache_v.dtype))
     else:
-        cache_k = lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype),
-                                           (0, index, 0, 0))
-        cache_v = lax.dynamic_update_slice(cache_v, v.astype(cache_v.dtype),
-                                           (0, index, 0, 0))
-    o = ops.decode_attention(q, cache_k, cache_v, index + 1,
+        at = (layer, 0, index, 0, 0)
+        cache_k = lax.dynamic_update_slice(
+            cache_k, k[None].astype(cache_k.dtype), at)
+        cache_v = lax.dynamic_update_slice(
+            cache_v, v[None].astype(cache_v.dtype), at)
+    o = ops.decode_attention(q, cache_k, cache_v, index + 1, layer,
                              seq_shard=seq_shard)      # (B, 1, H, hd)
     o = o.reshape(B, one, n_heads * head_dim)
     return o @ p["wo"], cache_k, cache_v

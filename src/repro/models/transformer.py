@@ -171,8 +171,11 @@ def decode_step(cfg: ArchConfig, params: Params, cache: Params,
     from .sharding import current_rules
     zero_decode = bool(current_rules().get("fsdp"))
 
-    def body(h, xs):
-        blk, ck, cv = xs
+    def body(carry, xs):
+        # the whole stacked cache is carried: each layer writes its new row
+        # in place and attention reads the stack at that layer
+        h, ck, cv = carry
+        blk, layer = xs
         # ZeRO-sharded decode: keep the tiny activation sharded on D over
         # 'fsdp' so projections contract against *local* weight shards
         # (activation psums, bytes ~B*D) instead of all-gathering each
@@ -181,18 +184,21 @@ def decode_step(cfg: ArchConfig, params: Params, cache: Params,
         if zero_decode:
             h = shard(h, None, None, "fsdp")
         hn = L.rms_norm(blk["norm1"], h, cfg.norm_eps)
-        o, ck, cv = L.attention_decode(blk["attn"], hn, ck, cv, index,
-                                       n_heads=cfg.n_heads,
-                                       n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
-                                       theta=cfg.rope_theta, eps=cfg.norm_eps)
+        o, ck, cv = L.attention_decode_at(blk["attn"], hn, ck, cv, layer,
+                                          index, n_heads=cfg.n_heads,
+                                          n_kv=cfg.n_kv_heads,
+                                          head_dim=cfg.hd,
+                                          theta=cfg.rope_theta,
+                                          eps=cfg.norm_eps)
         h = h + o
         hn = L.rms_norm(blk["norm2"], h, cfg.norm_eps)
         if zero_decode:
             hn = shard(hn, None, None, "fsdp")
         h = h + L.mlp_block(blk["mlp"], hn)
-        return h, (ck, cv)
+        return (h, ck, cv), None
 
-    x, (ks, vs) = lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
+    (x, ks, vs), _ = lax.scan(body, (x, cache["k"], cache["v"]),
+                              (params["blocks"], jnp.arange(cfg.n_layers)))
     logits = logits_of(cfg, params, x)
     new_cache = {"k": ks, "v": vs, "index": index + 1}
     return logits, new_cache

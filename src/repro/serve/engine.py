@@ -29,13 +29,16 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
 
 from .. import tracing
 from ..configs.base import ArchConfig
 from ..models.model import bundle_for
 
 __all__ = ["Request", "ServeEngine", "UnsupportedFamilyError",
-           "SUPPORTED_FAMILIES"]
+           "SUPPORTED_FAMILIES", "serve_programs"]
 
 # model families the continuous-batching engine can decode; serving
 # endpoints advertise exactly this set in their capability record, so the
@@ -68,6 +71,36 @@ class Request:
     submitted: Optional[float] = None
 
 
+def serve_programs(cfg: ArchConfig, sharding):
+    """The engine's decode step and prefill-into-slot programs, and the
+    formats its cache lives in on ``sharding``'s device.
+
+    Both programs take the cache donated and return it updated in place:
+    decode writes each slot's new row, prefill the prompt's rows and length
+    at a traced ``slot`` (rows past the prompt keep stale values, which the
+    lengths mask).  K and V are kept row-major, the layout the decode
+    kernel reads: XLA's default for narrow heads (qwen2-0.5b's K 2, hd 64)
+    puts ``Smax`` minor, and each call would relayout the whole cache.
+    """
+    bundle = bundle_for(cfg)
+    kv = Format(Layout(major_to_minor=tuple(range(5))), sharding)
+    formats = {"k": kv, "v": kv, "index": sharding}
+    out = (None, {"k": kv, "v": kv, "index": None})
+
+    def prefill_into(params, cache, tokens, slot):
+        logits, c1 = bundle.prefill(cfg, params, tokens)
+        at = (0, slot, 0, 0, 0)
+        return logits, {
+            "k": lax.dynamic_update_slice(cache["k"], c1["k"], at),
+            "v": lax.dynamic_update_slice(cache["v"], c1["v"], at),
+            "index": cache["index"].at[slot].set(tokens.shape[1])}
+
+    decode = jax.jit(lambda p, c, t: bundle.decode_step(cfg, p, c, t),
+                     donate_argnums=1, out_shardings=out)
+    prefill = jax.jit(prefill_into, donate_argnums=1, out_shardings=out)
+    return decode, prefill, formats
+
+
 class ServeEngine:
     def __init__(self, cfg: ArchConfig, params, *, max_batch: int = 4,
                  max_seq: int = 256, greedy: bool = True, device=None,
@@ -78,17 +111,14 @@ class ServeEngine:
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.record_logits = record_logits
-        bundle = bundle_for(cfg)
-        self._decode = jax.jit(
-            lambda p, c, t: bundle.decode_step(cfg, p, c, t))
-        self._prefill = jax.jit(
-            lambda p, t: bundle.prefill(cfg, p, t, max_seq=max_seq),
-            static_argnames=())
-        cache = bundle.init_cache(cfg, max_batch, max_seq)
+        sharding = SingleDeviceSharding(device or jax.devices()[0])
+        self._decode, self._prefill, formats = serve_programs(cfg, sharding)
+        cache = bundle_for(cfg).init_cache(cfg, max_batch, max_seq)
         # vectorized per-slot positions
         cache["index"] = jnp.zeros((max_batch,), jnp.int32)
         if device is not None:
-            params, cache = jax.device_put((params, cache), device)
+            params = jax.device_put(params, device)
+        cache = jax.device_put(cache, formats)
         self.params = params
         self.cache = cache
         self.slots: List[Optional[Request]] = [None] * max_batch
@@ -145,12 +175,8 @@ class ServeEngine:
             if rec is not None and req.submitted is not None:
                 rec["queued_s"] = rec["t0"] - req.submitted
             toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            logits, c1 = self._prefill(self.params, toks)
-            # copy the single-row cache into the slot
-            self.cache["k"] = self.cache["k"].at[:, slot].set(c1["k"][:, 0])
-            self.cache["v"] = self.cache["v"].at[:, slot].set(c1["v"][:, 0])
-            self.cache["index"] = self.cache["index"].at[slot].set(
-                len(req.prompt))
+            logits, self.cache = self._prefill(self.params, self.cache, toks,
+                                               np.int32(slot))
             with tracing.span("serve.sync"):
                 nxt = int(jnp.argmax(logits[0, -1]))
                 if self.record_logits:

@@ -101,34 +101,71 @@ def test_chunked_attention_matches_ref():
 # flash decode
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("B,Smax,H,K,hd,length,bk", [
-    (2, 512, 8, 2, 64, 300, 128),
-    (1, 1024, 4, 4, 128, 1024, 256),
-    (2, 256, 4, 1, 32, 7, 64),           # nearly-empty cache
-    (3, 384, 6, 2, 48, 200, 128),
-])
-def test_flash_decode(B, Smax, H, K, hd, length, bk):
+def _decode_inputs(B, Smax, H, K, hd, L):
+    """A query and K/V caches of one layer (``L`` None) or stacked over
+    ``L`` layers."""
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, 1, H, hd), jnp.float32)
-    ck = jax.random.normal(ks[1], (B, Smax, K, hd), jnp.float32)
-    cv = jax.random.normal(ks[2], (B, Smax, K, hd), jnp.float32)
-    out = flash_decode(q, ck, cv, jnp.asarray(length), block_k=bk,
+    cache = ((B,) if L is None else (L, B)) + (Smax, K, hd)
+    return (jax.random.normal(ks[0], (B, 1, H, hd), jnp.float32),
+            jax.random.normal(ks[1], cache, jnp.float32),
+            jax.random.normal(ks[2], cache, jnp.float32))
+
+
+def _decode_ids(cases, n):
+    """One layer's cases keep their ids (the first ``n`` numbers); stacked
+    ones show every number and the layer read."""
+    return ["-".join(map(str, c[:n])) if c[-2] is None
+            else "-".join(map(str, c[:-2])) + f"-L{c[-2]}-layer{c[-1]}"
+            for c in cases]
+
+
+_DECODE_CASES = [
+    (2, 512, 8, 2, 64, 300, 128, None, 0),
+    (1, 1024, 4, 4, 128, 1024, 256, None, 0),
+    (2, 256, 4, 1, 32, 7, 64, None, 0),           # nearly-empty cache
+    (3, 384, 6, 2, 48, 200, 128, None, 0),
+    # a stacked (L, B, Smax, K, hd) cache read at its first or last layer
+    (2, 512, 8, 2, 64, 300, 128, 3, 0),
+    (2, 256, 4, 1, 32, 7, 64, 3, 2),
+    (3, 384, 6, 2, 48, 200, 128, 2, 1),
+    (2, 512, 16, 8, 128, 511, 128, 4, 3),
+]
+
+
+@pytest.mark.parametrize("B,Smax,H,K,hd,length,bk,L,layer", _DECODE_CASES,
+                         ids=_decode_ids(_DECODE_CASES, 7))
+def test_flash_decode(B, Smax, H, K, hd, length, bk, L, layer):
+    q, ck, cv = _decode_inputs(B, Smax, H, K, hd, L)
+    out = flash_decode(q, ck, cv, jnp.asarray(length), layer, block_k=bk,
                        interpret=True)
+    if L is not None:
+        ck, cv = ck[layer], cv[layer]
     want = ref.decode_attention_ref(q, ck, cv, jnp.asarray(length))
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("Smax,bk", [(512, 128), (300, 128)])
-def test_flash_decode_ragged_lengths(Smax, bk):
+_RAGGED_CASES = [
+    (512, 128, 2, 64, None, 0),
+    (300, 128, 2, 64, None, 0),
+    # stacked caches: first and last layer, Smax not whole blocks
+    (300, 128, 2, 64, 4, 0),
+    (300, 128, 8, 128, 4, 3),
+    (300, 128, 1, 32, 2, 1),
+    (520, 256, 2, 48, 3, 2),
+]
+
+
+@pytest.mark.parametrize("Smax,bk,K,hd,L,layer", _RAGGED_CASES,
+                         ids=_decode_ids(_RAGGED_CASES, 2))
+def test_flash_decode_ragged_lengths(Smax, bk, K, hd, L, layer):
     """Per-row (B,) lengths, as a continuous batch decodes; a cache that is
-    not a whole number of blocks is padded and masked."""
-    ks = jax.random.split(KEY, 3)
-    B, H, K, hd = 4, 8, 2, 64
-    q = jax.random.normal(ks[0], (B, 1, H, hd), jnp.float32)
-    ck = jax.random.normal(ks[1], (B, Smax, K, hd), jnp.float32)
-    cv = jax.random.normal(ks[2], (B, Smax, K, hd), jnp.float32)
+    not a whole number of blocks is masked past its end."""
+    B, H = 4, 8
+    q, ck, cv = _decode_inputs(B, Smax, H, K, hd, L)
     lengths = jnp.asarray([1, 77, Smax, 129], jnp.int32)
-    out = flash_decode(q, ck, cv, lengths, block_k=bk, interpret=True)
+    out = flash_decode(q, ck, cv, lengths, layer, block_k=bk, interpret=True)
+    if L is not None:
+        ck, cv = ck[layer], cv[layer]
     want = ref.decode_attention_ref(q, ck, cv, lengths)
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 

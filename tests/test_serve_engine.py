@@ -5,10 +5,13 @@ import dataclasses
 import jax
 import numpy as np
 import pytest
+from jax import lax
 
 from repro.configs.base import get_config, smoke_of
 from repro.kernels.ops import use_impl
 from repro.models import bundle_for
+from repro.models import layers as L
+from repro.models import transformer as T
 from repro.serve.engine import (SUPPORTED_FAMILIES, ServeEngine,
                                 UnsupportedFamilyError)
 
@@ -174,3 +177,73 @@ def test_engine_through_kernels_matches_reference_logits():
         np.testing.assert_allclose(np.stack(got.logits),
                                    np.stack(want.logits), atol=1e-4,
                                    rtol=1e-4)
+
+
+def test_cache_is_donated_not_copied(demo):
+    """An admission and a decode step each take the cache donated: the
+    buffer they were given is gone, not copied beside the new one."""
+    cfg, params = demo
+    eng = ServeEngine(cfg, params, max_batch=2, max_seq=64)
+    eng.submit([1, 2, 3, 4], max_new=4)
+    before = eng.cache["k"]
+    eng._admit()
+    assert before.is_deleted() and not eng.cache["k"].is_deleted()
+    before = eng.cache["k"]
+    eng.step()
+    assert before.is_deleted() and not eng.cache["k"].is_deleted()
+
+
+def _per_layer_decode_step(cfg, params, cache, tokens):
+    """The dense decode step on 4-D per-layer caches: each layer's K/V is
+    scanned out of the stack and written back whole."""
+    index = cache["index"]
+
+    def body(h, xs):
+        blk, ck, cv = xs
+        hn = L.rms_norm(blk["norm1"], h, cfg.norm_eps)
+        o, ck, cv = L.attention_decode(blk["attn"], hn, ck, cv, index,
+                                       n_heads=cfg.n_heads,
+                                       n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
+                                       theta=cfg.rope_theta, eps=cfg.norm_eps)
+        h = h + o
+        hn = L.rms_norm(blk["norm2"], h, cfg.norm_eps)
+        return h + L.mlp_block(blk["mlp"], hn), (ck, cv)
+
+    x = L.embed_lookup(params["embed"], tokens)
+    x, (ks, vs) = lax.scan(body, x, (params["blocks"], cache["k"],
+                                     cache["v"]))
+    return T.logits_of(cfg, params, x), {"k": ks, "v": vs,
+                                         "index": index + 1}
+
+
+def test_engine_matches_per_request_per_layer_decode(demo):
+    """A continuous batch on the stacked, in-place cache gives each request
+    the tokens and logit rows of that request decoded alone on per-layer
+    caches."""
+    cfg = dataclasses.replace(demo[0], dtype="float32")
+    params = bundle_for(cfg).init(cfg, KEY)
+    max_seq, max_new, rows = 64, 6, 4
+    rng = np.random.default_rng(5)
+    prompts = [list(rng.integers(0, cfg.vocab, n)) for n in (5, 17, 9)]
+    eng = ServeEngine(cfg, params, max_batch=2, max_seq=max_seq,
+                      record_logits=rows)
+    reqs = [eng.submit(p, max_new=max_new) for p in prompts]
+    eng.run()
+
+    prefill = jax.jit(lambda p, t: T.prefill(cfg, p, t, max_seq=max_seq))
+    step = jax.jit(lambda p, c, t: _per_layer_decode_step(cfg, p, c, t))
+    for prompt, req in zip(prompts, reqs):
+        logits, cache = prefill(params, np.asarray([prompt], np.int32))
+        out, want_rows = [], []
+        while True:
+            row = np.asarray(logits[0, -1], np.float32)
+            want_rows.append(row)
+            out.append(int(np.argmax(row)))
+            if len(out) == max_new:
+                break
+            logits, cache = step(params, cache,
+                                 np.asarray([[out[-1]]], np.int32))
+        assert req.out == out
+        np.testing.assert_allclose(np.stack(req.logits),
+                                   np.stack(want_rows[:rows]), atol=1e-5,
+                                   rtol=1e-5)

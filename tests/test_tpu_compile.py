@@ -8,6 +8,7 @@ tests and only the one running this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,10 +16,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
+from repro.kernels import ops
 from repro.kernels.decode_attention import flash_decode
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_gating import moe_gating
 from repro.kernels.ssd_scan import ssd_state_scan
+from repro.models import bundle_for
+from repro.serve.engine import serve_programs
 
 
 @pytest.fixture(scope="module")
@@ -65,6 +69,90 @@ def test_flash_decode_per_row_lengths(one_chip, arch):
     lengths = _sds((B,), jnp.int32, one_chip)
     text = _compiled_text(flash_decode, q, cache, cache, lengths)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "qwen2-0.5b"])
+def test_flash_decode_stacked_cache(one_chip, arch):
+    cfg = get_config(arch)
+    B, Smax = 8, 1024
+    q = _sds((B, 1, cfg.n_heads, cfg.hd), cfg.dtype, one_chip)
+    cache = _sds((cfg.n_layers, B, Smax, cfg.n_kv_heads, cfg.hd), cfg.dtype,
+                 one_chip)
+    lengths = _sds((B,), jnp.int32, one_chip)
+    layer = _sds((), jnp.int32, one_chip)
+    text = _compiled_text(flash_decode, q, cache, cache, lengths, layer)
+    assert "tpu_custom_call" in text
+
+
+def _engine_program(one_chip, arch, B, Smax, which):
+    """The serve engine's own decode (or prefill-into-slot) program,
+    compiled for the described chip with the Pallas kernels."""
+    cfg = get_config(arch)
+    bundle = bundle_for(cfg)
+    with ops.use_impl("pallas"):
+        decode, prefill, formats = serve_programs(cfg, one_chip)
+        params = jax.eval_shape(lambda: bundle.init(cfg, jax.random.PRNGKey(0)))
+        cache = jax.eval_shape(lambda: bundle.init_cache(cfg, B, Smax))
+        cache["index"] = jax.ShapeDtypeStruct((B,), jnp.int32)
+        cache = {k: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=formats[k])
+                 for k, a in cache.items()}
+        params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                              params)
+        if which == "decode":
+            args = (params, cache, _sds((B, 1), jnp.int32, one_chip))
+            text = decode.lower(*args).compile().as_text()
+        else:
+            args = (params, cache, _sds((1, 1280), jnp.int32, one_chip),
+                    _sds((), jnp.int32, one_chip))
+            text = prefill.lower(*args).compile().as_text()
+    return cfg, text
+
+
+def _cache_ops(text, shapes):
+    """Instructions that copy, transpose, slice or update a cache-sized
+    array: ``[(name, op, shape)]``."""
+    found = []
+    for m in re.finditer(r"%([\w.-]+) = \w+\[([\d,]*)\]\S* "
+                         r"(copy|transpose|dynamic-slice|"
+                         r"dynamic-update-slice)\(", text):
+        if m.group(2) in shapes:
+            found.append(m.groups())
+    return found
+
+
+@pytest.mark.parametrize("arch,B,Smax", [("qwen3-1.7b", 16, 2048),
+                                         ("qwen2-0.5b", 16, 2048)])
+def test_decode_step_reads_and_writes_the_cache_in_place(one_chip, arch, B,
+                                                         Smax):
+    """The engine's decode step: the donated cache is aliased to the
+    output, the decode kernel is there by name, and no cache-sized array is
+    copied, transposed, sliced out or written back — per layer or whole."""
+    cfg, text = _engine_program(one_chip, arch, B, Smax, "decode")
+    K, hd, L = cfg.n_kv_heads, cfg.hd, cfg.n_layers
+    shapes = {f"{B},{Smax},{K},{hd}", f"{B},{K},{Smax},{hd}",
+              f"{L},{B},{Smax},{K},{hd}"}
+    header = text.splitlines()[0]            # holds input_output_alias
+    entry = text[text.index("\nENTRY "):]
+    cache_params = re.findall(
+        rf"= bf16\[{L},{B},{Smax},{K},{hd}\]\S* parameter\((\d+)\)", entry)
+    assert len(cache_params) == 2
+    for n in cache_params:
+        assert f"({n}, {{}}, may-alias)" in header
+    assert re.search(r"%flash_decode\.\d+ = .* custom-call\(", text)
+    assert _cache_ops(text, shapes) == []
+
+
+def test_prefill_writes_its_slot_in_place(one_chip):
+    """Prefill into a slot: the donated cache is aliased and only the
+    prompt's rows are written, with no copy of the whole cache."""
+    cfg, text = _engine_program(one_chip, "qwen3-1.7b", 16, 2048, "prefill")
+    stacked = f"{cfg.n_layers},16,2048,{cfg.n_kv_heads},{cfg.hd}"
+    entry = text[text.index("\nENTRY "):]
+    for n in re.findall(rf"= bf16\[{stacked}\]\S* parameter\((\d+)\)",
+                        entry):
+        assert f"({n}, {{}}, may-alias)" in text.splitlines()[0]
+    assert not [op for op in _cache_ops(text, {stacked})
+                if op[2] != "dynamic-update-slice"]
 
 
 def test_flash_attention_vjp_qwen3(one_chip):
